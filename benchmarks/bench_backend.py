@@ -150,6 +150,9 @@ def main() -> None:
     if args.quick:
         args.batch, args.repeat = 8, 20
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.core.workloads import WORKLOADS
     from repro.obs.metrics import export_bench
 

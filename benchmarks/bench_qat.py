@@ -97,6 +97,9 @@ def main() -> None:
     if args.quick:
         args.steps = min(args.steps, 80)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     budgets = [int(b) for b in args.budgets.split(",")]
     results, prev = [], {}
     for budget in budgets:

@@ -281,10 +281,12 @@ def main() -> None:
 
     import jax
 
+    from repro.compile_cache import enable_compile_cache
     from repro.configs import get_config
     from repro.models import get_model
     from repro.serve import derive_kv_spec
 
+    enable_compile_cache()
     cfg = get_config("qwen2-1.5b", reduced=True)
     model = get_model(cfg)
     params = model.init(jax.random.PRNGKey(0))

@@ -9,6 +9,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models import get_model
 from repro.quant.quantizer import pack_weights_int8
@@ -17,6 +18,7 @@ from repro.serve import (Request, ServingConfig, ServingEngine,
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = get_config("qwen2-1.5b", reduced=True)
     model = get_model(cfg)
     params = model.init(jax.random.PRNGKey(0))

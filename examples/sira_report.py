@@ -53,7 +53,7 @@ def arch_report(args) -> "SiraModel":
     print(f"\nlayer-tail style for {args.a_bits}-bit activations: {style}")
     print(f"  thresholding: {tc.thresholding_luts:,.0f} LUTs | "
           f"composite fixed16.8: {tc.composite_luts:,.0f} LUTs")
-    print("TPU mapping: accumulator dtype "
+    print("TPU mapping: int32 MXU accumulation, stored as "
           f"{'int16' if s['mean_sira'] <= 15 else 'int32'}, fused "
           f"multithreshold tail (1 HBM pass)")
     return model

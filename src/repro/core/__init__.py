@@ -32,8 +32,7 @@ from .thresholds import (convert_tails_to_thresholds,      # noqa: F401
                          convert_tails, ThresholdConversionError,
                          TailReport, ThresholdSpec)
 from .accumulator import (minimize_accumulators, datatype_bound_bits,  # noqa: F401
-                          sira_bits, summarize, accumulator_dtype,
-                          exact_worst_case_bits)
+                          sira_bits, summarize, exact_worst_case_bits)
 from . import costmodel  # noqa: F401  (lazy shim over dataflow.costmodel)
 from .verify import verify_ranges, instrument, stuck_channels  # noqa: F401
 from .passes import (Transformation, Fixpoint, Sequence,   # noqa: F401

@@ -178,13 +178,3 @@ def summarize(reports: List[AccumulatorReport]) -> Dict[str, float]:
                 reduction_vs_datatype=1.0 - mu_s / mu_d,
                 reduction_vs_32b=1.0 - mu_s / 32.0)
 
-
-def accumulator_dtype(bits: int):
-    """TPU adaptation: map an exact SIRA bitwidth to the accumulation dtype
-    used by the Pallas integer matmul kernel (DESIGN.md §2)."""
-    import jax.numpy as jnp
-    if bits <= 15:
-        return jnp.int16
-    if bits <= 31:
-        return jnp.int32
-    return jnp.int64

@@ -10,7 +10,11 @@ end-to-end build flow demonstrates):
 
   * integer ``MatMul``/``Conv`` (im2col, grouped) → :func:`kernels.int_matmul`
     with ``acc_bits`` taken from the SIRA accumulator bound of the output
-    range (§4.2 — int16 tiles when the lossless width ≤ 15 bits);
+    range (§4.2 — the kernel accumulates in int32 and stores a raw
+    accumulator as int16 when the lossless width ≤ 15 bits).  Operands
+    that do not fit int8 take an exact int32 XLA dot instead (plan kinds
+    ``int_dot`` / ``int_conv_dot``): the TPU's MXU kernel path accepts no
+    int32 operands;
   * ``MultiThreshold`` → the fused :func:`kernels.multithreshold` kernel
     (transposing the graph's (C, N) threshold layout to the kernel's
     (N, C), handling ``axis`` and the ``out_scale``/``out_bias`` attrs);
@@ -43,6 +47,7 @@ interpret mode (``use_pallas=True, interpret=True``, the validation path).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -51,6 +56,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
+from repro.kernels import ref as kref
 
 from ..obs.trace import get_tracer
 from .graph import Graph, Node, quant_bounds
@@ -63,6 +69,9 @@ Env = Dict[str, jnp.ndarray]
 
 INT_DTYPE = jnp.int32
 MAX_INT32_BITS = 31
+# float matmuls run at full f32 precision: the TPU's default f32 dot
+# rounds its operands to bf16
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 class LoweringError(NotImplementedError):
@@ -75,8 +84,9 @@ class LoweredOp:
     """One plan entry — which kernel/route a node was lowered to."""
     node_name: str
     op_type: str
-    kind: str            # "int_matmul" | "int_conv" | "multithreshold" |
-    #                      "quantize" | "const_fold" | "jnp" | "fused:<...>"
+    kind: str            # "int_matmul" | "int_conv" | "int_dot" |
+    #                      "int_conv_dot" | "multithreshold" | "quantize" |
+    #                      "const_fold" | "jnp" | "fused:<...>"
     acc_bits: Optional[int] = None
 
 
@@ -122,6 +132,13 @@ class _Lowerer:
     # ------------------------------------------------------------- helpers
     def _kargs(self) -> Dict[str, Any]:
         return dict(use_pallas=self.use_pallas, interpret=self.interpret)
+
+    def _int_matmul_fn(self, in8: bool) -> Callable:
+        """Integer (M,K)@(K,N): the int8 kernel, or an exact int32 XLA dot
+        for operands wider than int8."""
+        if in8:
+            return functools.partial(kops.int_matmul, **self._kargs())
+        return kref.int_matmul_ref
 
     def _const(self, name: str, as_int: bool = False) -> np.ndarray:
         """Constant as a dtype-converted *numpy* array.  Numpy (never jnp)
@@ -318,7 +335,7 @@ class _Lowerer:
             def run(env: Env) -> None:
                 a = self._getf(env, a_t)
                 b = self._getf(env, b_t)
-                env[out] = a @ b
+                env[out] = jnp.matmul(a, b, precision=HIGHEST)
             self.is_int[out] = False
             self._push(run)
             self.plan.append(LoweredOp(node.name, "MatMul", "jnp"))
@@ -327,10 +344,11 @@ class _Lowerer:
         acc_bits = self._acc_bits(out)
         in8 = self._fits(a_t, -128, 127) and self._fits(b_t, -128, 127)
         in_dtype = jnp.int8 if in8 else INT_DTYPE
+        matmul = self._int_matmul_fn(in8)
+        kind = "int_matmul" if in8 else "int_dot"
         wq = jnp.asarray(w, in_dtype)
         K = int(w.shape[0])
         fused = self.fuse_epilogue and self._epilogue_chain(node)
-        kargs = self._kargs()
         if fused:
             scale, bias, fused_nodes, final = fused
             s_arr, b_arr = jnp.asarray(scale), jnp.asarray(bias)
@@ -338,29 +356,28 @@ class _Lowerer:
             def run(env: Env) -> None:
                 a = self._get(env, a_t)
                 lead = a.shape[:-1]
-                y = kops.int_matmul(a.reshape(-1, K).astype(in_dtype), wq,
-                                    s_arr, b_arr, acc_bits=acc_bits,
-                                    out_dtype=jnp.float32, **kargs)
+                y = matmul(a.reshape(-1, K).astype(in_dtype), wq,
+                           s_arr, b_arr, acc_bits=acc_bits,
+                           out_dtype=jnp.float32)
                 env[final] = y.reshape(lead + (y.shape[-1],))
             for n in fused_nodes:
                 self._skip.add(n.name)
             self.is_int[final] = False
             self._push(run)
             self.plan.append(LoweredOp(node.name, "MatMul",
-                                       "fused:int_matmul+epilogue",
+                                       f"fused:{kind}+epilogue",
                                        acc_bits=acc_bits))
             return
 
         def run(env: Env) -> None:
             a = self._get(env, a_t)
             lead = a.shape[:-1]
-            y = kops.int_matmul(a.reshape(-1, K).astype(in_dtype), wq,
-                                acc_bits=acc_bits, out_dtype=INT_DTYPE,
-                                **kargs)
+            y = matmul(a.reshape(-1, K).astype(in_dtype), wq,
+                       acc_bits=acc_bits, out_dtype=INT_DTYPE)
             env[out] = y.reshape(lead + (y.shape[-1],))
         self.is_int[out] = True
         self._push(run)
-        self.plan.append(LoweredOp(node.name, "MatMul", "int_matmul",
+        self.plan.append(LoweredOp(node.name, "MatMul", kind,
                                    acc_bits=acc_bits))
 
     def _lower_gemm(self, node: Node) -> None:
@@ -438,7 +455,7 @@ class _Lowerer:
         int_ok = (_integral(w) and self._tensor_is_int(x_t)
                   and acc_bits <= MAX_INT32_BITS
                   and (b_t is None or _integral(self.consts[b_t])))
-        dtype, kargs = self.dtype, self._kargs()
+        dtype = self.dtype
         # no epilogue fusion over a biased conv: the kernel epilogue runs
         # scale/bias on the raw accumulator, but the conv bias must be
         # added *before* the Mul/Add chain
@@ -447,6 +464,7 @@ class _Lowerer:
         if int_ok:
             in8 = self._fits(x_t, -128, 127) and self._fits(w_t, -128, 127)
             in_dtype = jnp.int8 if in8 else INT_DTYPE
+            matmul = self._int_matmul_fn(in8)
             wmats = [jnp.asarray(
                 w[g * og:(g + 1) * og].reshape(og, cin_g * kh * kw).T,
                 in_dtype) for g in range(groups)]
@@ -476,13 +494,12 @@ class _Lowerer:
                     if fused:
                         sg = jnp.asarray(scale[g * og:(g + 1) * og])
                         bg = jnp.asarray(bias[g * og:(g + 1) * og])
-                    y2 = kops.int_matmul(
+                    y2 = matmul(
                         a2.astype(in_dtype), wmats[g], sg, bg,
                         acc_bits=acc_bits,
-                        out_dtype=jnp.float32 if fused else INT_DTYPE,
-                        **kargs)
+                        out_dtype=jnp.float32 if fused else INT_DTYPE)
                 else:
-                    y2 = a2 @ wmats[g]
+                    y2 = jnp.matmul(a2, wmats[g], precision=HIGHEST)
                 yg = jnp.swapaxes(y2.reshape(n, p, og), 1, 2)
                 outs.append(yg.reshape(n, og, ho, wo))
             y = outs[0] if groups == 1 else jnp.concatenate(outs, axis=1)
@@ -492,8 +509,11 @@ class _Lowerer:
             env[final] = y
         self.is_int[final] = bool(int_ok and not fused)
         self._push(run)
-        kind = ("fused:int_conv+epilogue" if fused
-                else ("int_conv" if int_ok else "jnp"))
+        kind = "jnp"
+        if int_ok:
+            kind = "int_conv" if in8 else "int_conv_dot"
+            if fused:
+                kind = f"fused:{kind}+epilogue"
         self.plan.append(LoweredOp(node.name, "Conv", kind,
                                    acc_bits=acc_bits if int_ok else None))
 
@@ -754,6 +774,11 @@ class CompiledSiraModel:
                 return {k: np.asarray(v) for k, v in out.items()}
         out = self._jfn({k: np.asarray(v) for k, v in feeds.items()})
         return {k: np.asarray(v) for k, v in out.items()}
+
+    def lowered(self, feeds: Dict[str, Any]) -> Any:
+        """The jitted forward lowered for these feeds' shapes;
+        ``.compile().as_text()`` shows which kernels it runs."""
+        return self._jfn.lower({k: np.asarray(v) for k, v in feeds.items()})
 
     def profile(self, feeds: Dict[str, Any]) -> Dict[str, np.ndarray]:
         """Per-kernel dispatch timing: execute the step closures eagerly

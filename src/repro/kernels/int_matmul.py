@@ -5,10 +5,12 @@ This is the MXU realization of the paper's streamlined integer MatMul
 (§4.1.2) + accumulator minimization (§4.2):
 
   * inputs are int8 (the revealed integer kernel), multiplied on the MXU's
-    native 8-bit path with integer accumulation;
-  * the accumulator dtype is *selected from the SIRA bound*: int16 tiles
-    when the lossless width ≤ 15 bits (halving VMEM accumulator footprint,
-    allowing 2× larger fused tiles), else int32;
+    native 8-bit path and always accumulated in int32 (Mosaic accepts no
+    narrower MXU accumulator on TPU v5e);
+  * the SIRA bound selects the *output storage* dtype of the raw
+    accumulator: int16 when the lossless width ≤ 15 bits (halving the
+    bytes written to HBM), else int32 — a cast in the epilogue, exact
+    whenever the bound holds;
   * the single aggregated scale/bias (the whole layer tail after
     aggregation) is applied as a fused epilogue on the final K step —
     exactly one HBM pass for matmul + tail.
@@ -58,7 +60,8 @@ def int_matmul(x: jnp.ndarray, w: jnp.ndarray,
                interpret: bool = False) -> jnp.ndarray:
     """x (M, K) int8 @ w (K, N) int8 → int accumulate → optional dequant.
 
-    acc_bits: SIRA-minimized accumulator width; ≤15 selects int16 tiles.
+    acc_bits: SIRA-minimized accumulator width; ≤15 stores the raw
+        accumulator as int16 (accumulation is int32 either way).
     scale/bias: per-output-channel (N,) aggregated layer-tail parameters;
     if given, output is float32 (dequantized), else the raw accumulator.
     """
@@ -67,10 +70,10 @@ def int_matmul(x: jnp.ndarray, w: jnp.ndarray,
     assert K == K2
     assert M % bm == 0 and N % bn == 0 and K % bk == 0, \
         f"shape ({M},{K},{N}) not divisible by block ({bm},{bk},{bn})"
-    acc_dtype = jnp.int16 if acc_bits <= 15 else jnp.int32
     dequant = scale is not None
     if out_dtype is None:
-        out_dtype = jnp.float32 if dequant else acc_dtype
+        out_dtype = (jnp.float32 if dequant
+                     else jnp.int16 if acc_bits <= 15 else jnp.int32)
     if scale is None:
         scale = jnp.ones((N,), jnp.float32)
     if bias is None:
@@ -93,8 +96,6 @@ def int_matmul(x: jnp.ndarray, w: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn),
-                                   jnp.int16 if acc_bits <= 15
-                                   else jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
     )(x, w, scale2, bias2)

@@ -11,13 +11,14 @@ def int_matmul_ref(x: jnp.ndarray, w: jnp.ndarray,
                    scale: Optional[jnp.ndarray] = None,
                    bias: Optional[jnp.ndarray] = None,
                    acc_bits: int = 32, out_dtype=None) -> jnp.ndarray:
-    acc_dtype = jnp.int16 if acc_bits <= 15 else jnp.int32
+    """Exact integer dot (int32 accumulation, for int8 or int32 operands);
+    ``acc_bits`` ≤ 15 stores the raw accumulator as int16, as the kernel
+    does (lossless when the SIRA bound holds — tested)."""
     acc = jax.lax.dot_general(x, w, (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.int32)
-    acc = acc.astype(acc_dtype)  # emulate narrow accumulation (lossless if
-    #                              the SIRA bound holds — tested)
     if scale is None:
-        return acc.astype(out_dtype or acc_dtype)
+        return acc.astype(out_dtype or (jnp.int16 if acc_bits <= 15
+                                        else jnp.int32))
     y = acc.astype(jnp.float32) * scale.reshape(1, -1).astype(jnp.float32) \
         + (0.0 if bias is None else bias.reshape(1, -1).astype(jnp.float32))
     return y.astype(out_dtype or jnp.float32)

@@ -39,7 +39,7 @@ from repro.models import get_model
 from repro.optim.adamw import AdamW, AdamWState
 from repro.quant.quantizer import QuantSpec
 from repro.roofline.analysis import model_flops_for, roofline_from
-from repro.roofline.hlo_cost import analyze_hlo, normalize_cost_analysis
+from repro.roofline.hlo_cost import analyze_hlo
 from repro.train.train_step import TrainState, init_train_state, \
     make_train_step
 
@@ -137,13 +137,9 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     pspecs = param_pspecs(make_params_shapes())
 
-    # jax >= 0.5 activates a mesh with jax.set_mesh; on older releases the
-    # Mesh object itself is the context manager.
-    set_mesh = getattr(jax, "set_mesh", None) or (lambda m: m)
-
     t0 = time.time()
     try:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             if shape.kind == "train":
                 optimizer = AdamW(total_steps=1000)
                 step_fn = make_train_step(model, optimizer,
@@ -235,7 +231,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     except Exception as e:  # pragma: no cover
         mem["error"] = str(e)
 
-    cost_xla = normalize_cost_analysis(compiled.cost_analysis())
+    cost_xla = dict(compiled.cost_analysis())
     hlo = compiled.as_text()
     if save_hlo:
         with open(save_hlo, "w") as f:

@@ -10,24 +10,28 @@ module never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _make_mesh(shape, axes, devices=None):
+    # Auto axes: placement follows the argument shardings and the in-model
+    # shard() constraints.  jax.make_mesh's default Explicit axes reject
+    # ops whose output sharding is ambiguous, e.g. the embedding gather on
+    # the vocab-sharded table.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_debug_mesh(n_devices: int = 1):
-    """Small mesh over whatever devices exist (tests / CPU)."""
-    n = min(n_devices, len(jax.devices()))
+    """Small (data, model) mesh over the first ``n_devices`` devices
+    (capped at the number that exist); "model" is 2 wide when n is even."""
+    devices = jax.devices()[:n_devices]
+    n = len(devices)
     model = 2 if n % 2 == 0 else 1
-    return jax.make_mesh((n // model, model), ("data", "model"))
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh`` for in-jit ``shard()``
-    constraints — ``jax.set_mesh`` on jax >= 0.5, the ``Mesh`` object
-    itself (it is a context manager) on the pinned 0.4.x."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+    return _make_mesh((n // model, model), ("data", "model"), devices)

@@ -11,7 +11,7 @@ Conventions:
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -23,30 +23,6 @@ from repro.quant.quantizer import QuantSpec, compute_scale, fake_quant, \
 
 # --------------------------------------------------------------- sharding
 
-def _abstract_mesh():
-    """Current abstract mesh, or None outside any mesh context.
-
-    ``jax.sharding.get_abstract_mesh`` only exists on jax >= 0.5; on older
-    releases (the pinned 0.4.x) fall back to the active ``Mesh`` context
-    tracked by the thread resource env."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    try:
-        from jax._src.mesh import thread_resources
-        pm = thread_resources.env.physical_mesh
-    except Exception:
-        return None
-    if pm is None or pm.empty:
-        return None
-    return getattr(pm, "abstract_mesh", pm)
-
-
-def _mesh_axes() -> Sequence[str]:
-    m = _abstract_mesh()
-    return tuple(m.axis_names) if m is not None and m.axis_names else ()
-
-
 def shard(x: jnp.ndarray, *spec) -> jnp.ndarray:
     """with_sharding_constraint filtered to the current mesh's axes AND to
     divisible dims (a non-divisible constraint makes GSPMD pad the tensor —
@@ -54,8 +30,8 @@ def shard(x: jnp.ndarray, *spec) -> jnp.ndarray:
     buffers 8x; dropping the axis keeps them exact and replicated).
 
     spec entries: None, an axis name, or a tuple of axis names."""
-    m = _abstract_mesh()
-    if m is None or not m.axis_names:
+    m = jax.sharding.get_abstract_mesh()
+    if not m.axis_names:
         return x
     axes = set(m.axis_names)
     sizes = dict(zip(m.axis_names, m.axis_sizes))

@@ -383,11 +383,3 @@ def analyze_hlo(text: str, pod_group_size: Optional[int] = None) -> Totals:
     if mod.entry is None:
         return Totals()
     return mod.totals_for(mod.entry, pod_group_size)
-
-
-def normalize_cost_analysis(cost) -> Dict[str, float]:
-    """XLA ``Compiled.cost_analysis()`` returns a ``[dict]`` on jax < 0.5
-    and a plain dict on newer releases; normalize to a dict."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)

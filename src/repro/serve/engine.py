@@ -219,8 +219,7 @@ class ServingEngine:
         ``shard()`` constraints; a no-op without a configured mesh."""
         if self.mesh is None:
             return contextlib.nullcontext()
-        from repro.launch.mesh import use_mesh
-        return use_mesh(self.mesh)
+        return jax.set_mesh(self.mesh)
 
     # ------------------------------------------------------- paged mode
     def submit(self, request: Request) -> int:

@@ -38,7 +38,7 @@ def _feeds(model, batch=2, seed=0):
 def test_backend_bitexact_vs_interpreter(name):
     """Pallas kernels in interpret mode, float64: the integer core is
     bit-exact and the float outputs match to 1e-12."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         model = _optimized(name)
         compiled = model.compile(use_pallas=True, interpret=True)
         assert compiled.int_tensors, "no integer tensors lowered"
@@ -78,8 +78,8 @@ def test_backend_fast_path_matches(name):
 
 def test_sira_acc_bits_reach_the_kernel():
     """The lowering takes acc_bits from the SIRA accumulator bound — the
-    low-bit workloads must select narrow (≤15-bit → int16) accumulators
-    for at least one integer matmul/conv."""
+    low-bit workloads must prove a narrow (≤15-bit, stored as int16)
+    accumulator for at least one integer matmul/conv."""
     model = _optimized("TFC-w2a2")
     compiled = model.compile()
     acc = [op.acc_bits for op in compiled.plan
@@ -192,6 +192,36 @@ def test_integer_gemm_gets_int_matmul_route():
                       extra_outputs=compiled.int_tensors)
     got = compiled2(feeds)
     assert all(t in got for t in compiled.int_tensors)
+
+
+@pytest.mark.parametrize("op", ["MatMul", "Conv"])
+def test_wide_integer_operands_take_int32_dot(op):
+    """Operands that do not fit int8 (a 10-bit input quantizer) never
+    reach the int8 kernel: they take the exact int32 dot route, named in
+    the plan, and stay bit-exact."""
+    from repro.core import Graph, ScaledIntRange
+    rng = np.random.default_rng(0)
+    g = Graph(inputs=["X"], outputs=["Y"])
+    one = g.add_initializer(1.0)
+    zero = g.add_initializer(0.0)
+    b10 = g.add_initializer(10.0)
+    g.add_node("Quant", ["X", one, zero, b10], ["Xq"], dict(signed=1))
+    if op == "MatMul":
+        w = rng.integers(-3, 4, size=(16, 6)).astype(np.float64)
+        shape, kind = (3, 16), "int_dot"
+    else:
+        w = rng.integers(-3, 4, size=(4, 2, 3, 3)).astype(np.float64)
+        shape, kind = (3, 2, 6, 6), "int_conv_dot"
+    g.add_node(op, ["Xq", g.add_initializer(w, "W")], ["Y"],
+               dict(stride=1, pad=1) if op == "Conv" else {})
+    model = SiraModel(g, {"X": ScaledIntRange(lo=np.full((), -500.0),
+                                              hi=np.full((), 500.0))})
+    compiled = lower(model, dtype=jnp.float32, use_pallas=True,
+                     interpret=True)
+    assert [o.kind for o in compiled.plan if o.op_type == op] == [kind]
+    feeds = {"X": np.random.default_rng(1).uniform(-500, 500, size=shape)}
+    np.testing.assert_array_equal(compiled(feeds)["Y"].astype(np.float64),
+                                  model.execute(feeds)["Y"])
 
 
 def test_extra_outputs_validated_at_lower_time():

@@ -50,7 +50,8 @@ def test_int_matmul_fused_dequant():
 
 
 def test_int_matmul_sira_int16_accumulator():
-    """SIRA bound <= 15 bits → int16 accumulation, still exact."""
+    """SIRA bound <= 15 bits → the int32 accumulator is stored as int16,
+    still exact."""
     rng = np.random.default_rng(2)
     # |acc| <= 128*3*3 = 1152 < 2^14
     x = rng.integers(-3, 4, size=(128, 128)).astype(np.int8)

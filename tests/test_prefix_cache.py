@@ -481,7 +481,7 @@ prompt = np.asarray(%r)
 print("TOKENS", eng.generate([Request(prompt=prompt,
                                       max_new_tokens=4)])[0])
 """ % (prompt.tolist(),)
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=(os.environ.get("XLA_FLAGS", "") +
                           " --xla_force_host_platform_device_count=2"),
                PYTHONPATH=os.pathsep.join(

@@ -212,7 +212,8 @@ def test_padded_batch_matches_solo(setup):
     toks[1, L - len(short_p):] = short_p            # left-pad
     valid_from = jnp.asarray(np.array([0, L - len(short_p)], np.int32))
     fixed = prefill(toks, valid_from, 2)
-    np.testing.assert_allclose(fixed[1], solo[0], rtol=0, atol=1e-5)
+    # ulp scale: batch 2 vs batch 1 may reorder the f32 reductions
+    np.testing.assert_allclose(fixed[1], solo[0], rtol=1e-6, atol=1e-5)
     # sanity: without masking the pad garbage visibly corrupts the logits
     buggy = prefill(toks, None, 2)
     assert np.abs(buggy[1] - solo[0]).max() > 1e-3
