@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the device."""
+
+
+def read(rec):
+    if rec.trace is None:
+        return None
+    return 100.0 * rec.trace.idle_share
