@@ -5,9 +5,13 @@ of the repo — the FINN-R lesson that a dataflow-DSE framework lives or
 dies on the quality of its per-stage reports:
 
 * :mod:`repro.obs.trace` — nested spans + counters with Chrome
-  ``trace_event`` JSON export (Perfetto / ``chrome://tracing``).  A
-  process-global default tracer is a no-op until enabled, so the
-  instrumentation in ``core/flow.py``, ``core/propagate.py``,
+  ``trace_event`` JSON export (Perfetto / ``chrome://tracing``), or,
+  with ``enable_tracing(annotate=jax.profiler.TraceAnnotation)``
+  around a ``jax.profiler`` trace, spans written into the profiler's
+  own trace beside the device's ops (Perfetto / TensorBoard), where
+  the serving engine's ``engine:*`` phases explain the device's idle
+  time.  A process-global default tracer is a no-op until enabled, so
+  the instrumentation in ``core/flow.py``, ``core/propagate.py``,
   ``core/lower.py``, ``serve/engine.py`` and ``dataflow/folding.py``
   costs one flag check when disabled.
 * :mod:`repro.obs.metrics` — typed Counter / Gauge / Histogram registry

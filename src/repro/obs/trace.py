@@ -1,4 +1,5 @@
-"""Tracing: nested spans, counters, Chrome ``trace_event`` JSON export.
+"""Tracing: nested spans, counters, Chrome ``trace_event`` JSON export,
+and a sink into a profiler's own trace.
 
 A :class:`Tracer` records *spans* (named, nested, attributed wall-clock
 intervals) and *counters* (monotonic named tallies).  The process-global
@@ -6,20 +7,43 @@ default tracer (:func:`get_tracer`) is **disabled** until
 :func:`enable_tracing` is called: a disabled tracer's ``span()`` returns
 a shared no-op singleton and ``count()`` returns after one flag check,
 so instrumented hot paths (the compiled backend dispatch, the serving
-decode loop) pay nothing measurable — ``bench_backend.py`` gates the
+step) pay nothing measurable — ``bench_backend.py`` gates the
 enabled-tracer overhead on the compiled TFC path under 5%.
 
-Export is the Chrome ``trace_event`` JSON format (the ``traceEvents``
-array of ``"ph": "X"`` complete events and ``"ph": "C"`` counter
-events), loadable in Perfetto or ``chrome://tracing``:
+Two ways out:
 
-    from repro.obs.trace import enable_tracing, get_tracer
-    tracer = enable_tracing()
-    result = build_flow(make_cnv())        # spans recorded
-    tracer.write_chrome_trace("out.json")
+* **Chrome JSON** (the default).  Spans are kept in memory and exported
+  as the Chrome ``trace_event`` format (the ``traceEvents`` array of
+  ``"ph": "X"`` complete events and ``"ph": "C"`` counter events),
+  loadable in Perfetto or ``chrome://tracing``::
+
+      from repro.obs.trace import enable_tracing, get_tracer
+      tracer = enable_tracing()
+      result = build_flow(make_cnv())        # spans recorded
+      tracer.write_chrome_trace("out.json")
+
+* **A profiler sink.**  Given ``annotate`` (name and keyword attributes
+  -> context manager), every span also enters ``annotate(name,
+  **attrs)`` for its lifetime and is not kept in memory.  With
+  ``jax.profiler.TraceAnnotation`` the spans land in the profiler's
+  ``.xplane.pb`` on the same clock as the device's ops, so each idle
+  stretch of the device can be put down to the host code open over
+  it (read in Perfetto or TensorBoard's profile plugin)::
+
+      import jax
+      from repro.obs.trace import enable_tracing, disable_tracing
+      jax.profiler.start_trace("/tmp/prof")
+      enable_tracing(annotate=jax.profiler.TraceAnnotation)
+      engine.run()                       # engine:* spans in the trace
+      disable_tracing()
+      jax.profiler.stop_trace()
+
+  Attributes set on a span after it opened reach the annotation through
+  its ``set_metadata``, where it has one (``TraceAnnotation`` does).
 
 Everything here is stdlib-only by design — the observability layer must
-never constrain what it observes.
+never constrain what it observes; the profiler is handed in by the
+caller.
 """
 from __future__ import annotations
 
@@ -28,9 +52,13 @@ import json
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Union
+from typing import (Any, Callable, ContextManager, Dict, List, Optional,
+                    Union)
 
 Number = Union[int, float]
+#: a profiler sink: span name and attributes -> a context manager that
+#: is open for the span's lifetime (e.g. ``jax.profiler.TraceAnnotation``)
+Annotate = Callable[..., ContextManager[Any]]
 
 #: ``ph`` values this module emits (and :func:`validate_chrome_trace`
 #: accepts): complete spans, counter samples, metadata.
@@ -69,9 +97,12 @@ NULL_SPAN = _NullSpan()
 class _Span:
     """Context manager recording one span on exit.  An exception
     propagating through the span closes it with an ``error`` attr — a
-    failed build flow still produces a usable trace."""
+    failed build flow still produces a usable trace.  With a profiler
+    sink the span is open in the sink for its lifetime instead of being
+    recorded."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_t0", "_depth", "dur_s")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_depth", "dur_s",
+                 "_note")
 
     def __init__(self, tracer: "Tracer", name: str,
                  attrs: Dict[str, Any]):
@@ -81,27 +112,38 @@ class _Span:
         self._t0 = 0.0
         self._depth = 0
         self.dur_s: Optional[float] = None   # set on exit
+        self._note: Optional[ContextManager[Any]] = None
 
     def set_attr(self, key: str, value: Any) -> None:
         self.attrs[key] = value
+        forward = getattr(self._note, "set_metadata", None)
+        if forward is not None:
+            forward(**{key: value})
 
     def __enter__(self) -> "_Span":
-        stack = self._tracer._stack()
+        tracer = self._tracer
+        stack = tracer._stack()
         self._depth = len(stack)
         stack.append(self)
-        self._t0 = self._tracer.clock()
-        self._tracer._touch_epoch(self._t0)
+        if tracer.annotate is not None:
+            self._note = tracer.annotate(self.name, **self.attrs)
+            self._note.__enter__()
+        self._t0 = tracer.clock()
+        tracer._touch_epoch(self._t0)
         return self
 
     def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> None:
         t1 = self._tracer.clock()
         self.dur_s = t1 - self._t0
         if exc_type is not None:
-            self.attrs["error"] = f"{exc_type.__name__}: {exc}"
+            self.set_attr("error", f"{exc_type.__name__}: {exc}")
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
-        self._tracer._record(self, t1)
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
+        else:
+            self._tracer._record(self, t1)
         return None
 
 
@@ -110,13 +152,16 @@ class Tracer:
 
     ``clock`` is injectable (seconds, monotonic) so tests can drive
     deterministic time; the epoch is the first clock sample so exported
-    timestamps start near zero.
+    timestamps start near zero.  ``annotate`` is a profiler sink (see
+    the module docstring): spans go there and ``spans`` stays empty.
     """
 
     def __init__(self, enabled: bool = True,
-                 clock: Callable[[], float] = time.perf_counter):
+                 clock: Callable[[], float] = time.perf_counter,
+                 annotate: Optional[Annotate] = None):
         self.enabled = enabled
         self.clock = clock
+        self.annotate = annotate
         self.spans: List[SpanRecord] = []      # completion order
         self.counters: Dict[str, float] = {}   # cumulative totals
         self._counter_events: List[Dict[str, Any]] = []
@@ -240,10 +285,11 @@ def set_tracer(tracer: Tracer) -> Tracer:
     return tracer
 
 
-def enable_tracing(clock: Callable[[], float] = time.perf_counter
-                   ) -> Tracer:
-    """Install (and return) a fresh enabled global tracer."""
-    return set_tracer(Tracer(enabled=True, clock=clock))
+def enable_tracing(clock: Callable[[], float] = time.perf_counter,
+                   annotate: Optional[Annotate] = None) -> Tracer:
+    """Install (and return) a fresh enabled global tracer; with
+    ``annotate``, its spans go to that profiler sink."""
+    return set_tracer(Tracer(enabled=True, clock=clock, annotate=annotate))
 
 
 def disable_tracing() -> None:

@@ -41,6 +41,14 @@ exactly the KV the slot would have recomputed.  A ``mesh`` on the config
 shards params and the KV page pools (KV-head dim over the "model" axis)
 and runs every jitted call under the mesh context for tensor-parallel
 decode.
+
+Each phase of a paged step is an ``engine:*`` span of the global tracer
+(``repro.obs.trace``), one flag check while tracing is off.  A wait on
+the device (``engine:prefill_sync``, ``engine:decode_sync``) is a span
+of its own, so with the tracer's profiler sink on every idle stretch of
+the device lines up with the phase the host was in.  The paged step runs
+as three jits, ``paged_prefill``, ``paged_decode`` and ``paged_verify``,
+one shape each, so a profile tells them apart by name.
 """
 from __future__ import annotations
 
@@ -205,10 +213,21 @@ class ServingEngine:
                                        self.cache, self.metrics)
             kv_scales = spec.scales()
             page_size = cfg.page_size
-            self._step_fn = jax.jit(
-                lambda p, t, pages, table, lens: model.decode_paged(
-                    p, t, pages, table, lens, page_size=page_size,
-                    quant=quant, kv_scales=kv_scales))
+
+            def paged_step(name):
+                """The paged step as a jit of its own name, so that its
+                executable reads ``jit_<name>`` in a profile."""
+                def step(p, t, pages, table, lens):
+                    return model.decode_paged(
+                        p, t, pages, table, lens, page_size=page_size,
+                        quant=quant, kv_scales=kv_scales)
+                step.__name__ = step.__qualname__ = name
+                return jax.jit(step)
+
+            # one shape each: (1, prefill_chunk), (B, 1), (B, spec_k + 1)
+            self._prefill_fn = paged_step("paged_prefill")
+            self._decode_fn = paged_step("paged_decode")
+            self._verify_fn = paged_step("paged_verify")
         else:
             self._decode = jax.jit(
                 lambda p, t, c, i, v: model.decode_step(
@@ -236,12 +255,16 @@ class ServingEngine:
         sched = self.scheduler
         if not sched.has_work():
             return False
-        for slot, entry in sched.admit():
-            self._prefill(slot, entry)
-        if self.drafter is not None:
-            self._decode_spec()
-        else:
-            self._decode_once()
+        tr = get_tracer()
+        with tr.span("engine:step"):
+            with tr.span("engine:admit"):
+                admitted = sched.admit()
+            for slot, entry in admitted:
+                self._prefill(slot, entry)
+            if self.drafter is not None:
+                self._decode_spec()
+            else:
+                self._decode_once()
         return True
 
     def run(self) -> None:
@@ -268,49 +291,57 @@ class ServingEngine:
         seq = entry.seq
         L = len(seq)
         C = self.prefill_chunk
-        cached = self.cache.attach_prefix(slot, seq)
-        # defensive: every page at/above the recompute frontier must be
-        # private before prefill writes land (no-op by construction —
-        # attach copies the boundary page)
-        assert self.cache.prepare_write(slot, cached)
-        if self.cache.prefix_cache_enabled:
-            self.metrics.on_prefix_lookup(cached, L)
-        table = self.cache.slot_table(slot)
-        logits = None
+        req = entry.request
+        handle = entry.handle
         tr = get_tracer()
-        with tr.span("serve:prefill", slot=slot, prompt_tokens=L,
-                     chunk=C, cached_tokens=cached):
-            for start in range(cached, L, C):
-                chunk = seq[start:start + C]
-                toks = np.zeros((1, C), np.int32)
-                toks[0, :len(chunk)] = chunk
-                with tr.span("serve:prefill_chunk", start=start):
-                    with self._mesh_scope():
-                        logits, pages = self._step_fn(
+        with tr.span("engine:prefill", handle=handle, slot=slot,
+                     prompt_tokens=L) as sp:
+            with tr.span("engine:prefix_attach"):
+                cached = self.cache.attach_prefix(slot, seq)
+                # defensive: every page at/above the recompute frontier
+                # must be private before prefill writes land (no-op by
+                # construction — attach copies the boundary page)
+                assert self.cache.prepare_write(slot, cached)
+            starts = range(cached, L, C)
+            sp.set_attr("cached_tokens", cached)
+            sp.set_attr("chunks", len(starts))
+            if self.cache.prefix_cache_enabled:
+                self.metrics.on_prefix_lookup(cached, L)
+            table = self.cache.slot_table(slot)
+            logits = None
+            with self._mesh_scope():
+                for start in starts:
+                    with tr.span("engine:prefill_chunk"):
+                        chunk = seq[start:start + C]
+                        toks = np.zeros((1, C), np.int32)
+                        toks[0, :len(chunk)] = chunk
+                        logits, pages = self._prefill_fn(
                             self.params, jnp.asarray(toks),
                             self.cache.pages, table,
                             jnp.full((1,), start, jnp.int32))
-                self.cache.pages = pages
-                self.metrics.on_prefill_chunk()
-        self.scheduler.set_prefilled(slot, L)
-        # register before the first record_token: a request finishing on
-        # its very first token releases the slot right there, and only
-        # registered pages park in the reuse LRU
-        self.cache.register_prefix(slot, seq[:len(entry.request.prompt)])
+                        self.cache.pages = pages
+                        self.metrics.on_prefill_chunk()
+            self.scheduler.set_prefilled(slot, L)
+            # register before the first record_token: a request finishing
+            # on its very first token releases the slot right there, and
+            # only registered pages park in the reuse LRU
+            with tr.span("engine:register_prefix"):
+                self.cache.register_prefix(slot, seq[:len(req.prompt)])
 
-        req = entry.request
-        last = (L - 1 - cached) % C    # last prompt token in final chunk
-        with self._mesh_scope():
-            tok = self._sample_fn(
-                logits[:, last],
-                jnp.full((1,), req.temperature, jnp.float32),
-                jnp.full((1,), entry.prng_id, jnp.int32),
-                jnp.full((1,), entry.n_generated, jnp.int32))
-        handle = entry.handle
-        done = self.scheduler.record_token(slot, int(np.asarray(tok)[0]))
-        self.metrics.on_token(handle)
-        if done:
-            self.metrics.on_finish(handle)
+            last = (L - 1 - cached) % C  # last prompt token, final chunk
+            with tr.span("engine:prefill_sync"):
+                with self._mesh_scope():
+                    tok = self._sample_fn(
+                        logits[:, last],
+                        jnp.full((1,), req.temperature, jnp.float32),
+                        jnp.full((1,), entry.prng_id, jnp.int32),
+                        jnp.full((1,), entry.n_generated, jnp.int32))
+                token = int(np.asarray(tok)[0])
+            with tr.span("engine:record"):
+                done = self.scheduler.record_token(slot, token)
+                self.metrics.on_token(handle)
+                if done:
+                    self.metrics.on_finish(handle)
 
     def _grow_for_step(self, proposals=None) -> None:
         """Map page capacity for this step's per-slot write window.
@@ -343,44 +374,47 @@ class ServingEngine:
 
     def _decode_once(self) -> None:
         sched = self.scheduler
-        self._grow_for_step()
+        tr = get_tracer()
+        with tr.span("engine:grow"):
+            self._grow_for_step()
         active = sched.active_slots()
         if not active:
             return
-        with get_tracer().span("serve:decode_step", active=len(active),
-                               batch_slots=self.B):
-            self._decode_once_inner(sched, active)
-
-    def _decode_once_inner(self, sched, active) -> None:
         B = self.B
-        toks = np.zeros((B,), np.int32)
-        lens = np.zeros((B,), np.int32)
-        temps = np.zeros((B,), np.float32)
-        rids = np.zeros((B,), np.int32)
-        steps = np.zeros((B,), np.int32)
-        for i in active:
-            st = sched.slots[i]
-            toks[i] = st.entry.seq[-1]       # sampled, not yet cached
-            lens[i] = st.length
-            temps[i] = st.entry.request.temperature
-            rids[i] = st.entry.prng_id
-            steps[i] = st.entry.n_generated
         with self._mesh_scope():
-            logits, pages = self._step_fn(
-                self.params, jnp.asarray(toks)[:, None], self.cache.pages,
-                self.cache.device_table(), jnp.asarray(lens))
-            self.cache.pages = pages
-            nxt = np.asarray(self._sample_fn(
-                logits[:, -1], jnp.asarray(temps), jnp.asarray(rids),
-                jnp.asarray(steps)))
-        self.metrics.on_decode_step(len(active), B, tokens=len(active))
-        for i in active:
-            sched.note_cache_write(i)
-            handle = sched.slots[i].entry.handle
-            done = sched.record_token(i, int(nxt[i]))
-            self.metrics.on_token(handle)
-            if done:
-                self.metrics.on_finish(handle)
+            with tr.span("engine:decode_inputs"):
+                toks = np.zeros((B, 1), np.int32)
+                lens = np.zeros((B,), np.int32)
+                temps = np.zeros((B,), np.float32)
+                rids = np.zeros((B,), np.int32)
+                steps = np.zeros((B,), np.int32)
+                for i in active:
+                    st = sched.slots[i]
+                    toks[i, 0] = st.entry.seq[-1]  # sampled, not cached
+                    lens[i] = st.length
+                    temps[i] = st.entry.request.temperature
+                    rids[i] = st.entry.prng_id
+                    steps[i] = st.entry.n_generated
+                toks, lens = jnp.asarray(toks), jnp.asarray(lens)
+                table = self.cache.device_table()
+            with tr.span("engine:decode_dispatch"):
+                logits, pages = self._decode_fn(
+                    self.params, toks, self.cache.pages, table, lens)
+                self.cache.pages = pages
+                # the sampler's inputs go up while the device runs the step
+                nxt = self._sample_fn(logits[:, -1], jnp.asarray(temps),
+                                      jnp.asarray(rids), jnp.asarray(steps))
+            with tr.span("engine:decode_sync"):
+                nxt = np.asarray(nxt)
+        with tr.span("engine:record"):
+            self.metrics.on_decode_step(len(active), B, tokens=len(active))
+            for i in active:
+                sched.note_cache_write(i)
+                handle = sched.slots[i].entry.handle
+                done = sched.record_token(i, int(nxt[i]))
+                self.metrics.on_token(handle)
+                if done:
+                    self.metrics.on_finish(handle)
 
     # -------------------------------------------------- speculative decode
     def _decode_spec(self) -> None:
@@ -405,93 +439,99 @@ class ServingEngine:
         """
         sched = self.scheduler
         k = self.spec_k
-        proposals = {}
-        for i in sched.active_slots():
-            e = sched.slots[i].entry
-            remaining = e.request.max_new_tokens - e.n_generated
-            want = min(k, remaining - 1)   # last token never needs a draft
-            props = (self.drafter.propose(e.seq, want, e.prng_id)
-                     if want > 0 else [])
-            proposals[i] = [int(t) for t in props][:want]
+        tr = get_tracer()
+        with tr.span("engine:spec_propose"):
+            proposals = {}
+            for i in sched.active_slots():
+                e = sched.slots[i].entry
+                remaining = e.request.max_new_tokens - e.n_generated
+                want = min(k, remaining - 1)  # last token needs no draft
+                props = (self.drafter.propose(e.seq, want, e.prng_id)
+                         if want > 0 else [])
+                proposals[i] = [int(t) for t in props][:want]
         if not any(proposals.values()):
             self._decode_once()            # PR 3 path, bit-identical
             return
 
         T = k + 1
-        self._grow_for_step(proposals)
+        with tr.span("engine:grow"):
+            self._grow_for_step(proposals)
         active = sched.active_slots()
         if not active:
             return
-        with get_tracer().span("serve:spec_verify", active=len(active),
-                               spec_k=k) as sp:
-            self._verify_window(sched, proposals, active, T, sp)
+        self._verify_window(sched, proposals, active, T)
 
-    def _verify_window(self, sched, proposals, active, T, sp) -> None:
+    def _verify_window(self, sched, proposals, active, T) -> None:
         B = self.B
-        toks = np.zeros((B, T), np.int32)
-        lens = np.zeros((B,), np.int32)
-        for i in active:
-            st = sched.slots[i]
-            row = [st.entry.seq[-1]] + proposals.get(i, [])
-            toks[i, :len(row)] = row
-            lens[i] = st.length
+        tr = get_tracer()
         with self._mesh_scope():
-            logits, pages = self._step_fn(
-                self.params, jnp.asarray(toks), self.cache.pages,
-                self.cache.device_table(), jnp.asarray(lens))
-        self.cache.pages = pages
-
-        # sample every verify position in one vectorized call: row (i, t)
-        # uses the same (seed, request_id, token index) key the per-token
-        # path would, so acceptance == equality with the exact stream.
-        # All B*T rows are sampled (idle slots discarded) so the jitted
-        # sampler sees one stable shape — per-active-count shapes would
-        # retrace on every queue-depth change and dwarf the verify call.
-        temps = np.zeros((B * T,), np.float32)
-        rids = np.zeros((B * T,), np.int32)
-        steps = np.zeros((B * T,), np.int32)
-        for i in active:
-            e = sched.slots[i].entry
-            temps[i * T:(i + 1) * T] = e.request.temperature
-            rids[i * T:(i + 1) * T] = e.prng_id
-            steps[i * T:(i + 1) * T] = e.n_generated + np.arange(T)
-        sampled = np.asarray(self._sample_fn(
-            logits.reshape(B * T, -1), jnp.asarray(temps),
-            jnp.asarray(rids), jnp.asarray(steps))).reshape(B, T)
-
-        emitted_total = proposed = accepted_total = 0
-        for i in active:
-            props = proposals.get(i, [])
-            exp = sampled[i]
-            a = 0
-            while a < len(props) and props[a] == int(exp[a]):
-                a += 1
-            # emit accepted drafts + the correction/bonus token;
-            # record_tokens stops at EOS / max_new_tokens inside the
-            # window (slot + pages freed there, tail discarded)
-            emitted = [int(t) for t in exp[:a + 1]]
-            handle = sched.slots[i].entry.handle
-            n_rec, done = sched.record_tokens(i, emitted)
-            for _ in range(n_rec):
-                self.metrics.on_token(handle)
-            emitted_total += n_rec
-            proposed += len(props)
-            # drafts accepted AND emitted — an EOS/max_new termination
-            # inside the window discards the tail, which must not count
-            # toward the acceptance rate
-            accepted_total += min(a, n_rec)
-            if done:
-                self.metrics.on_finish(handle)
-            else:
-                sched.advance(i, 1 + a)          # pending + accepted
-                self.cache.rollback(i, sched.slots[i].length)
-                self.drafter.observe(sched.slots[i].entry.seq,
-                                     sched.slots[i].entry.prng_id)
-        self.metrics.on_decode_step(len(active), B, tokens=emitted_total)
-        self.metrics.on_spec_step(proposed, accepted_total)
-        sp.set_attr("proposed", proposed)
-        sp.set_attr("accepted", accepted_total)
-        sp.set_attr("emitted", emitted_total)
+            with tr.span("engine:decode_inputs"):
+                toks = np.zeros((B, T), np.int32)
+                lens = np.zeros((B,), np.int32)
+                for i in active:
+                    st = sched.slots[i]
+                    row = [st.entry.seq[-1]] + proposals.get(i, [])
+                    toks[i, :len(row)] = row
+                    lens[i] = st.length
+                toks, lens = jnp.asarray(toks), jnp.asarray(lens)
+                table = self.cache.device_table()
+            with tr.span("engine:decode_dispatch"):
+                logits, pages = self._verify_fn(
+                    self.params, toks, self.cache.pages, table, lens)
+                self.cache.pages = pages
+                # sample every verify position in one vectorized call: row
+                # (i, t) uses the same (seed, request_id, token index) key
+                # the per-token path would, so acceptance == equality with
+                # the exact stream.  All B*T rows are sampled (idle slots
+                # discarded) so the jitted sampler sees one stable shape —
+                # per-active-count shapes would retrace on every
+                # queue-depth change and dwarf the verify call.  Its
+                # inputs are built while the device runs the verify step.
+                temps = np.zeros((B * T,), np.float32)
+                rids = np.zeros((B * T,), np.int32)
+                steps = np.zeros((B * T,), np.int32)
+                for i in active:
+                    e = sched.slots[i].entry
+                    temps[i * T:(i + 1) * T] = e.request.temperature
+                    rids[i * T:(i + 1) * T] = e.prng_id
+                    steps[i * T:(i + 1) * T] = e.n_generated + np.arange(T)
+                sampled = self._sample_fn(
+                    logits.reshape(B * T, -1), jnp.asarray(temps),
+                    jnp.asarray(rids), jnp.asarray(steps))
+            with tr.span("engine:decode_sync"):
+                sampled = np.asarray(sampled).reshape(B, T)
+        with tr.span("engine:record"):
+            emitted_total = proposed = accepted_total = 0
+            for i in active:
+                props = proposals.get(i, [])
+                exp = sampled[i]
+                a = 0
+                while a < len(props) and props[a] == int(exp[a]):
+                    a += 1
+                # emit accepted drafts + the correction/bonus token;
+                # record_tokens stops at EOS / max_new_tokens inside the
+                # window (slot + pages freed there, tail discarded)
+                emitted = [int(t) for t in exp[:a + 1]]
+                handle = sched.slots[i].entry.handle
+                n_rec, done = sched.record_tokens(i, emitted)
+                for _ in range(n_rec):
+                    self.metrics.on_token(handle)
+                emitted_total += n_rec
+                proposed += len(props)
+                # drafts accepted AND emitted — an EOS/max_new termination
+                # inside the window discards the tail, which must not count
+                # toward the acceptance rate
+                accepted_total += min(a, n_rec)
+                if done:
+                    self.metrics.on_finish(handle)
+                else:
+                    sched.advance(i, 1 + a)          # pending + accepted
+                    self.cache.rollback(i, sched.slots[i].length)
+                    self.drafter.observe(sched.slots[i].entry.seq,
+                                         sched.slots[i].entry.prng_id)
+            self.metrics.on_decode_step(len(active), self.B,
+                                        tokens=emitted_total)
+            self.metrics.on_spec_step(proposed, accepted_total)
 
     # ---------------------------------------------------------- generate
     def generate(self, requests: List[Request]) -> List[List[int]]:

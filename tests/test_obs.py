@@ -100,6 +100,56 @@ def test_span_error_attr_on_exception():
     assert s.dur_us >= 0
 
 
+class _Note:
+    """A profiler annotation that logs its life into ``log``."""
+
+    def __init__(self, log, name, **attrs):
+        self.log, self.name = log, name
+        log.append(("enter", name, dict(attrs)))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, None))
+
+    def set_metadata(self, **attrs):
+        self.log.append(("meta", self.name, attrs))
+
+
+def test_profiler_sink_gets_every_span_and_keeps_none():
+    log = []
+    tr = Tracer(clock=FakeClock(),
+                annotate=lambda name, **a: _Note(log, name, **a))
+    with tr.span("outer", slot=1) as sp:
+        with tr.span("inner"):
+            pass
+        sp.set_attr("chunks", 3)
+    with pytest.raises(RuntimeError):
+        with tr.span("fails"):
+            raise RuntimeError("kaboom")
+    assert log == [("enter", "outer", {"slot": 1}),
+                   ("enter", "inner", {}), ("exit", "inner", None),
+                   ("meta", "outer", {"chunks": 3}),
+                   ("exit", "outer", None),
+                   ("enter", "fails", {}),
+                   ("meta", "fails", {"error": "RuntimeError: kaboom"}),
+                   ("exit", "fails", None)]
+    assert tr.spans == []                  # the sink holds them, not us
+    assert sp.dur_s is not None
+
+
+def test_enable_tracing_installs_the_sink():
+    def sink(name, **attrs):
+        return NULL_SPAN
+    try:
+        assert enable_tracing(annotate=sink).annotate is sink
+        assert get_tracer().annotate is sink
+        assert enable_tracing().annotate is None
+    finally:
+        disable_tracing()
+
+
 def test_counters_accumulate():
     tr = Tracer(clock=FakeClock())
     tr.count("hits")

@@ -160,7 +160,7 @@ def test_rejected_window_leaves_cache_state_exact(setup):
         toks[0, 0] = st.entry.seq[-1]
         lens = np.zeros((B,), np.int32)
         lens[0] = L
-        logits, _pages = eng._step_fn(      # discard pages: no commit
+        logits, _pages = eng._decode_fn(    # discard pages: no commit
             eng.params, jnp.asarray(toks), eng.cache.pages,
             eng.cache.device_table(), jnp.asarray(lens))
         return np.asarray(logits[0, 0].astype(jnp.float32))
@@ -173,8 +173,9 @@ def test_rejected_window_leaves_cache_state_exact(setup):
     toks[0] = (np.asarray(st.entry.seq[-1]) + np.arange(4) + 1) % cfg.vocab
     lens = np.zeros((B,), np.int32)
     lens[0] = L
-    _, pages = eng._step_fn(eng.params, jnp.asarray(toks), eng.cache.pages,
-                            eng.cache.device_table(), jnp.asarray(lens))
+    _, pages = eng._verify_fn(eng.params, jnp.asarray(toks),
+                              eng.cache.pages, eng.cache.device_table(),
+                              jnp.asarray(lens))
     eng.cache.pages = pages                 # garbage committed to pool...
     eng.cache.rollback(0, L)                # ...then rolled back
     assert len(eng.cache.free) == free_after_reserve, "no pool churn"
