@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import math
 from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
@@ -291,6 +292,19 @@ class PrefixIndex:
         return dropped
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def copy_page(pages, src, dst):
+    """``pages`` (any pytree of pools, page index first) with page
+    ``src`` of each pool written over page ``dst``.  The pools are
+    donated: each copy is a dynamic slice and a dynamic update slice on
+    the pool's own buffer, one page of bytes, whatever the pool's size,
+    dtype or sharding."""
+    def one(pool):
+        page = jax.lax.dynamic_slice_in_dim(pool, src, 1)
+        return jax.lax.dynamic_update_slice_in_dim(pool, page, dst, 0)
+    return jax.tree.map(one, pages)
+
+
 class PagedKVCache:
     """Shared physical page pool + host-side page table / free list.
 
@@ -410,9 +424,13 @@ class PagedKVCache:
                 for j in range(len(tokens) // ps)]
 
     def _copy_page(self, src: int, dst: int) -> None:
-        for pool in self.pages:
-            pool["k"] = pool["k"].at[dst].set(pool["k"][src])
-            pool["v"] = pool["v"].at[dst].set(pool["v"][src])
+        """Copy physical page ``src`` over ``dst`` in every pool of every
+        layer: one dispatch of :func:`copy_page`, which takes the pools
+        donated and writes the page in place.  The pages are traced
+        scalars, so the first copy compiles the one executable that
+        every later copy reuses; a warm-up request that shares part of
+        a page with an earlier one moves that compile out of serving."""
+        self.pages = copy_page(self.pages, np.int32(src), np.int32(dst))
 
     def attach_prefix(self, slot: int, tokens: Sequence[int]) -> int:
         """Map the longest cached prefix of ``tokens`` into the slot,
@@ -424,7 +442,9 @@ class PagedKVCache:
         returned frontier).  A mid-page overlap at the boundary is
         *copied* into the slot's own private page — copy-on-write done
         eagerly, because the slot will write the divergent suffix of
-        that very page during prefill.
+        that very page during prefill.  The copy is one dispatch of the
+        donated :func:`copy_page` executable over every layer's pools,
+        compiled once (see ``_copy_page``).
 
         Returns the recompute frontier: the number of leading tokens
         whose KV is already in the cache (< len(tokens); the last token

@@ -19,8 +19,10 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import get_model
-from repro.serve import (KVCacheSpec, PagedKVCache, PrefixIndex, Request,
-                         ServingConfig, ServingEngine, derive_kv_spec)
+from repro.serve import (KVCacheSpec, LayerKVSpec, PagedKVCache,
+                         PrefixIndex, Request, ServingConfig, ServingEngine,
+                         derive_kv_spec)
+from repro.serve.kv_cache import copy_page
 
 
 @pytest.fixture(scope="module")
@@ -259,6 +261,112 @@ def test_prepare_write_above_frontier_is_noop():
     # which lands in the slot's private tail page: nothing to fork
     assert cache.prepare_write(1, cached)
     assert cache.forks == forks
+
+
+# ---------------------------------------------------------------------------
+# the page copy: one donated executable over every pool
+# ---------------------------------------------------------------------------
+
+def _mixed_cache(**kw):
+    """Tiny cache whose first layer is fp32 and second int8, its pools
+    filled with seeded values; returns the cache and a numpy copy of
+    every pool.  The pools are built from the numpy arrays, never read
+    back: on the CPU a numpy view of a jax array pins its buffer, and a
+    pinned buffer is copied instead of donated."""
+    kw.setdefault("page_size", 4)
+    spec = KVCacheSpec((LayerKVSpec(int8=False),
+                        LayerKVSpec(int8=True, k_scale=np.ones(2),
+                                    v_scale=np.ones(2))))
+    cache = PagedKVCache(_TinyCfg, spec, 2, 16, prefix_cache=True, **kw)
+    rng = np.random.default_rng(3)
+    want = [{n: (rng.integers(-128, 128, p[n].shape).astype(np.int8)
+                 if p[n].dtype == jnp.int8 else
+                 rng.standard_normal(p[n].shape).astype(np.float32))
+             for n in ("k", "v")} for p in cache.pages]
+    cache.pages = [{n: jnp.asarray(w[n]) for n in w} for w in want]
+    return cache, want
+
+
+@pytest.mark.parametrize("src,dst", [(1, 8), (8, 1), (4, 5)])
+def test_copy_page_bit_exact_on_every_pool(src, dst):
+    cache, want = _mixed_cache()
+    assert [p["k"].dtype for p in cache.pages] == [jnp.float32, jnp.int8]
+    cache._copy_page(src, dst)
+    for got, w in zip(cache.pages, want):
+        for n in ("k", "v"):
+            w[n][dst] = w[n][src]            # only page dst changes
+            assert got[n].dtype == w[n].dtype
+            np.testing.assert_array_equal(np.asarray(got[n]), w[n])
+
+
+def test_copy_page_compiles_once_for_every_page_pair():
+    """The pages are traced: the first copy on a pool geometry compiles
+    the one executable (fp and int8 pools alike), and no later copy,
+    boundary copy at attach or fork, compiles again."""
+    cache, _ = _mixed_cache(page_size=2)       # a geometry of its own
+    n0 = copy_page._cache_size()
+    cache._copy_page(1, 2)
+    assert copy_page._cache_size() == n0 + 1
+    for src, dst in [(3, 4), (8, 1), (16, 5)]:
+        cache._copy_page(src, dst)
+    toks = list(range(4))
+    assert cache.grow(0, 4)
+    cache.register_prefix(0, toks)
+    assert cache.grow(1, 4)
+    assert cache.attach_prefix(1, toks[:3] + [42]) == 3   # boundary copy
+    assert cache.prepare_write(1, 0)                      # a fork
+    assert cache.forks == 2
+    assert copy_page._cache_size() == n0 + 1
+
+
+def test_copy_page_donates_the_pools():
+    cache, _ = _mixed_cache()
+    before = [p[n] for p in cache.pages for n in ("k", "v")]
+    cache._copy_page(2, 3)
+    assert all(a.is_deleted() for a in before)
+    assert not any(p[n].is_deleted() for p in cache.pages
+                   for n in ("k", "v"))
+
+
+def test_copy_page_keeps_the_pools_sharding():
+    """On a 2-device mesh (subprocess: the device count is fixed when
+    jax is imported) the copied pools keep their KV-head sharding."""
+    script = """
+import jax, jax.numpy as jnp, numpy as np
+assert len(jax.devices()) == 2, jax.devices()
+from repro.launch.mesh import make_debug_mesh
+from repro.launch.shardings import kv_pool_sharding
+from repro.serve import KVCacheSpec, PagedKVCache
+
+class Cfg:
+    n_layers, n_kv_heads, hd, dtype = 2, 2, 4, jnp.float32
+
+sharding = kv_pool_sharding(make_debug_mesh(2), Cfg.n_kv_heads)
+assert not sharding.is_fully_replicated
+cache = PagedKVCache(Cfg, KVCacheSpec.all_fp(2), 2, 16, page_size=4,
+                     prefix_cache=True, sharding=sharding)
+want = np.arange(np.prod(cache.pages[0]["k"].shape), dtype=np.float32)
+want = want.reshape(cache.pages[0]["k"].shape)
+cache.pages = [{n: jax.device_put(want, sharding) for n in ("k", "v")}
+               for _ in range(2)]
+cache._copy_page(3, 6)
+want[6] = want[3]
+for p in cache.pages:
+    for a in p.values():
+        assert a.sharding == sharding, a.sharding
+        np.testing.assert_array_equal(np.asarray(a), want)
+print("SHARDED_COPY_OK")
+"""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "") +
+                          " --xla_force_host_platform_device_count=2"),
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(os.path.dirname(__file__), "..", "src")]
+                   + sys.path))
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "SHARDED_COPY_OK" in out.stdout
 
 
 # ---------------------------------------------------------------------------

@@ -135,3 +135,25 @@ def test_paged_attention_layer_compiles(one_chip, pool_dtype):
         sds((B, n_pages), jnp.int32), sds((B,), jnp.int32)).compile()
     y, kp, _ = c.out_info
     assert y.shape == (B, T, cfg.d_model) and kp.dtype == pool_dtype
+
+
+def test_copy_page_is_in_place_at_qwen2_width(one_chip):
+    """The prefix cache's page copy over every pool of full-width
+    qwen2-1.5b (16 slots x 4096 in 8-token pages, one layer int8): the
+    donated pools alias the outputs and the copy needs no buffer of its
+    own, so it moves one page, not 1.88 GB of pools."""
+    from repro.serve.kv_cache import copy_page
+    cfg = get_config("qwen2-1.5b")
+    P = 16 * 4096 // 8 + 1
+
+    def pool(dtype):
+        return jax.ShapeDtypeStruct((P, 8, cfg.n_kv_heads, cfg.hd), dtype,
+                                    sharding=one_chip)
+    pages = [{"k": pool(d), "v": pool(d)}
+             for d in [jnp.int8] + [cfg.dtype] * (cfg.n_layers - 1)]
+    i = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    m = copy_page.lower(pages, i, i).compile().memory_analysis()
+    page_bytes = 8 * cfg.n_kv_heads * cfg.hd * 2
+    assert m.alias_size_in_bytes > 1e9
+    assert m.output_size_in_bytes - m.alias_size_in_bytes < page_bytes
+    assert m.temp_size_in_bytes < page_bytes
