@@ -36,7 +36,7 @@ def readings(cell, seed: int, seconds: float, control: bool):
                           process_start=PROCESS_START)
     harness.release(ses)
     picked = check.sample(rec.requests, seed)
-    prog = [float(check.served_gaps(cell.config, ses.w, r).max())
+    prog = [float(check.served_gaps(cell, ses.w, r).max())
             for r in picked]
     row = {"seed": seed, "requests": len(picked),
            "tokens": sum(len(r.tokens) for r in picked),
@@ -45,7 +45,7 @@ def readings(cell, seed: int, seconds: float, control: bool):
            "program_max_gap": max(prog, default=None),
            "program_gaps": prog}
     if control:
-        ctl = [float(check.control_gaps(cell.config, ses.w, r).max())
+        ctl = [float(check.control_gaps(cell, ses.w, r).max())
                for r in picked]
         row.update(control_max_gap=max(ctl, default=None),
                    control_gaps=ctl)

@@ -1,5 +1,6 @@
 """Whether a run is correct: what the timed path served, against the
-plain float32 reference.
+plain float32 reference of the configuration's architecture kind
+(``arch/<kind>.py``, ``logits_at``).
 
 After the window a sample of the finished greedy requests is drawn from
 the seed, the longest among them (most tokens served) always in it.  The
@@ -26,8 +27,7 @@ from typing import Any, Dict, List
 
 import numpy as np
 
-from benchmarks.chip import sampling
-from benchmarks.chip.reference import dense_gqa
+from benchmarks.chip import sampling, spec
 
 #: the sample: at least this many served tokens and requests, and at
 #: most this many requests
@@ -59,20 +59,21 @@ def _positions(r):
     return seq, rows
 
 
-def served_gaps(config, w, r) -> np.ndarray:
+def served_gaps(cell, w, r) -> np.ndarray:
     """Per served token: reference best logit minus the served token's."""
     seq, rows = _positions(r)
-    ref = dense_gqa.logits_at(config, w, seq, rows)
+    ref = spec.arch_kind(cell).logits_at(cell.config, w, seq, rows)
     toks = np.asarray(r.tokens)
     return ref.max(-1) - ref[np.arange(len(toks)), toks]
 
 
-def control_gaps(config, w, r) -> np.ndarray:
+def control_gaps(cell, w, r) -> np.ndarray:
     """Per served position: the reference's gap of the token that the
     float8 control ranks first."""
     seq, rows = _positions(r)
-    ref = dense_gqa.logits_at(config, w, seq, rows)
-    ctl = dense_gqa.logits_at(config, w, seq, rows, mode="fp8")
+    kind = spec.arch_kind(cell)
+    ref = kind.logits_at(cell.config, w, seq, rows)
+    ctl = kind.logits_at(cell.config, w, seq, rows, mode="fp8")
     pick = ctl.argmax(-1)
     return ref.max(-1) - ref[np.arange(len(pick)), pick]
 
@@ -81,7 +82,7 @@ def judge(rec, w, seed: int) -> Dict[str, Any]:
     """``{"correct": bool, "numbers": {name: {"value", "limit"}}}``."""
     limit = rec.cell.params.get("check", {}).get("max_logit_gap")
     picked = sample(rec.requests, seed)
-    gap = max((float(served_gaps(rec.cell.config, w, r).max())
+    gap = max((float(served_gaps(rec.cell, w, r).max())
                for r in picked), default=None)
     failed = sum(r.in_window and not r.finished for r in rec.requests)
     short = sum(r.in_window and r.finished and

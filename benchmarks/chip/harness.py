@@ -78,10 +78,6 @@ class Records:
     exe_labels: Dict[str, str] = dataclasses.field(default_factory=dict)
     peaks: Optional[Dict[str, float]] = None
 
-    @property
-    def model(self) -> Dict[str, Any]:
-        return self.cell.config["model"]
-
 
 class CompileCounter:
     """Counts executables that JAX lowers (compiled or loaded from the
@@ -107,27 +103,6 @@ def counters(eng) -> Dict[str, int]:
                 prefix_hit_tokens=m.prefix_hit_tokens,
                 prefix_lookup_tokens=m.prefix_lookup_tokens,
                 tokens=sum(r.n_tokens for r in m.requests.values()))
-
-
-def arch_config(config: Dict[str, Any]):
-    """The program's ``ArchConfig`` for a configuration file: the named
-    architecture, with every size taken from the file's published keys."""
-    import jax.numpy as jnp
-    from repro.configs import get_config
-
-    m = config["model"]
-    if m.get("hidden_act", "silu") != "silu":
-        raise ValueError("only SwiGLU (silu) models run on this path")
-    return dataclasses.replace(
-        get_config(config["arch"]),
-        n_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        n_heads=m["num_attention_heads"],
-        n_kv_heads=m["num_key_value_heads"], d_ff=m["intermediate_size"],
-        vocab=m["vocab_size"], head_dim=m.get("head_dim"),
-        qkv_bias=bool(m.get("attention_bias", False)),
-        rope_theta=float(m["rope_theta"]),
-        tie_embeddings=bool(m["tie_word_embeddings"]),
-        dtype=jnp.dtype(config["dtype"]))
 
 
 def start_profiler(log_dir: str) -> None:
@@ -370,10 +345,11 @@ def setup(cell: "spec.Cell", *, seed: int, seconds: float,
     compile_counter()
     phase = {"start": time.perf_counter()}
     config = cell.config
-    arch = arch_config(config)
+    kind = spec.arch_kind(cell)
+    arch = kind.arch_config(config)
     model = get_model(arch)
-    w = weights.make(config["model"], arch.vocab_padded, seed, arch.dtype)
-    params = weights.to_program(w, model)
+    w = weights.make(kind, config, arch.vocab_padded, seed, arch.dtype)
+    params = kind.to_program(w, model)
     phase["weights"] = time.perf_counter()
     eng = ServingEngine(model, params, ServingConfig(**config["engine"]))
     phase["engine"] = time.perf_counter()

@@ -1,9 +1,13 @@
 """Find a cell's parts by name: ``BENCHMARK.json``, the configuration's
-file, the traffic mix, the cell's own file and the per-layer readers.
+file, its architecture kind, the traffic mix, the cell's own file and
+the per-layer readers.
 
-Nothing here knows a particular configuration or mix: a new one is a
-new file under ``configs/``, ``traffic/``, ``cells/`` or ``metrics/``
-plus an entry in ``BENCHMARK.json``.
+Nothing here knows a particular configuration, architecture or mix: a
+new configuration of a known architecture is a new file under
+``configs/`` and ``cells/`` plus entries in ``BENCHMARK.json``; one of
+a new architecture adds its kind under ``arch/`` and its plain
+reference under ``reference/``; a new mix or per-layer metric is a file
+under ``traffic/`` or ``metrics/`` plus an entry.
 """
 from __future__ import annotations
 
@@ -13,8 +17,8 @@ import json
 from pathlib import Path
 from typing import Any, Dict, List, Optional
 
-#: this directory; configuration, traffic, cell and metric files live
-#: under it
+#: this directory; configuration, architecture, traffic, cell and metric
+#: files live under it
 HERE = Path(__file__).resolve().parent
 #: the checkout's root, where ``BENCHMARK.json`` lives
 ROOT = HERE.parents[1]
@@ -89,6 +93,14 @@ def load_cell(name: str, bench: Optional[Dict[str, Any]] = None,
     if not cfg_path.is_absolute():
         cfg_path = root / cfg_path
     config = load_json(cfg_path)
+    if "kind" not in config:
+        raise ValueError(f"{cfg_path} names no \"kind\": the module of its "
+                         f"architecture, {base / 'arch' / '<kind>.py'}")
+    kind_file = _kind_file(base, config)
+    if not kind_file.exists():
+        raise FileNotFoundError(f"{cfg_path} names kind "
+                                f"{config['kind']!r}, but there is no "
+                                f"{kind_file}")
     traffic = load_json(base / "traffic" / f"{w['traffic']}.json")
     cell_file = base / "cells" / f"{name}.json"
     params = load_json(cell_file) if cell_file.exists() else {}
@@ -100,6 +112,17 @@ def load_cell(name: str, bench: Optional[Dict[str, Any]] = None,
     return Cell(name=name, chips=int(w["chips"]), config=config,
                 traffic=traffic, params=params, end_to_end=e2e,
                 per_layer=per_layer, base=base)
+
+
+def _kind_file(base: Path, config: Dict[str, Any]) -> Path:
+    return base / "arch" / f"{config['kind']}.py"
+
+
+def arch_kind(cell: Cell):
+    """The module of the cell's architecture (``arch/<kind>.py``): its
+    program configuration, weights, reference and work counts."""
+    kind = cell.config["kind"]
+    return load_module(_kind_file(cell.base, cell.config), "arch." + kind)
 
 
 def traffic_kind(cell: Cell):

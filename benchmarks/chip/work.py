@@ -4,7 +4,7 @@ from __future__ import annotations
 
 from typing import Optional
 
-from benchmarks.chip import flops
+from benchmarks.chip import spec
 
 
 def prefilled(rec, lo: float, hi: float) -> int:
@@ -22,7 +22,8 @@ def flops_in(rec, lo: float, hi: float) -> int:
     [lo, hi): a prefill counts where its first token came out, from the
     end of any shared prefix to the end of the prompt; decoded token
     ``i >= 1`` attends from position ``len(prompt) + i - 1``."""
-    m = rec.model
+    config = rec.cell.config
+    kind = spec.arch_kind(rec.cell)
     total = 0
     for r in rec.requests:
         n = len(r.prompt)
@@ -30,9 +31,9 @@ def flops_in(rec, lo: float, hi: float) -> int:
             if not lo <= t < hi:
                 continue
             if i == 0:
-                total += flops.prefill_flops(m, r.shared_prefix, n)
+                total += kind.prefill_flops(config, r.shared_prefix, n)
             else:
-                total += flops.token_flops(m, n + i - 1, logits=True)
+                total += kind.token_flops(config, n + i - 1, logits=True)
     return total
 
 

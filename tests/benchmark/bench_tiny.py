@@ -17,7 +17,8 @@ MODEL = {"hidden_act": "silu", "hidden_size": 64, "intermediate_size": 128,
 SERVED = {"embedding_multiplier": 8.0, "attention_multiplier": 16 ** -0.5,
           "residual_multiplier": 1.0, "logits_scaling": 1.0,
           "rms_norm_eps": 1e-6}
-CONFIG = {"name": "tiny", "arch": "qwen2-1.5b", "dtype": "float32",
+CONFIG = {"name": "tiny", "arch": "qwen2-1.5b", "kind": "dense_gqa",
+          "dtype": "float32",
           "chips": 1,
           "engine": {"batch_slots": 4, "max_seq": 256, "kv_cache": "fp",
                      "prefix_cache": True},
@@ -38,8 +39,10 @@ LIMIT = 0.01
 def build(base: Path, limit: float = LIMIT) -> dict:
     """Write the tiny benchmark's files under ``base``; returns its
     ``BENCHMARK.json`` content (cells ``tiny.chat``, ``tiny.longctx``)."""
-    for d in ("traffic/kinds", "cells", "metrics", "configs"):
+    for d in ("arch", "traffic/kinds", "cells", "metrics", "configs"):
         (base / d).mkdir(parents=True, exist_ok=True)
+    for a in spec.HERE.glob("arch/*.py"):
+        shutil.copy(a, base / "arch")
     for k in spec.HERE.glob("traffic/kinds/*.py"):
         shutil.copy(k, base / "traffic" / "kinds")
     for m in spec.HERE.glob("metrics/*.py"):

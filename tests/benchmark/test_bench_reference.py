@@ -10,10 +10,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.chip import check, harness, weights
+from benchmarks.chip import check, spec, weights
 from benchmarks.chip.reference import dense_gqa
 
 import bench_tiny
+
+#: the tiny configuration as a cell of the committed benchmark would
+#: see it: its kind's module is found under the benchmark's own base
+CELL = types.SimpleNamespace(config=bench_tiny.CONFIG, base=spec.HERE)
+KIND = spec.arch_kind(CELL)
 
 PAGE, CHUNK = 8, 8
 
@@ -26,11 +31,10 @@ def served():
     from repro.serve import KVCacheSpec, PagedKVCache
 
     cfg = bench_tiny.CONFIG
-    arch = harness.arch_config(cfg)
+    arch = KIND.arch_config(cfg)
     model = get_model(arch)
-    w = weights.make(cfg["model"], arch.vocab_padded, 2 ** 31 + 7,
-                     arch.dtype)
-    params = weights.to_program(w, model)
+    w = weights.make(KIND, cfg, arch.vocab_padded, 2 ** 31 + 7, arch.dtype)
+    params = KIND.to_program(w, model)
     cache = PagedKVCache(arch, KVCacheSpec.all_fp(arch.n_layers), 2, 160,
                          page_size=PAGE)
     step = jax.jit(lambda p, t, pg, tb, ln: model.decode_paged(
@@ -57,7 +61,7 @@ def served():
     for _ in range(40):
         logits, cache.pages = step(params, jnp.asarray(last)[:, None],
                                    cache.pages, cache.device_table(),
-                                   jnp.asarray(lens))
+                                   jnp.array(lens))
         lens += 1
         for s in range(2):
             rows[s].append(np.asarray(logits)[s, 0, :V])
@@ -71,8 +75,8 @@ def test_reference_logits_match_the_paged_path(served):
     cfg, w, prompts, out, rows = served
     for prompt, toks, got in zip(prompts, out, rows):
         seq = np.concatenate([prompt, toks[:-1]])
-        want = dense_gqa.logits_at(cfg, w, seq,
-                                   len(prompt) - 1 + np.arange(len(toks)))
+        want = KIND.logits_at(cfg, w, seq,
+                              len(prompt) - 1 + np.arange(len(toks)))
         scale = np.abs(want).max()
         assert np.abs(got - want).max() <= 1e-4 * scale
 
@@ -92,7 +96,8 @@ class _R:
 
 def _judge(cfg, w, reqs):
     cell = types.SimpleNamespace(
-        config=cfg, params={"check": {"max_logit_gap": bench_tiny.LIMIT}})
+        config=cfg, base=spec.HERE,
+        params={"check": {"max_logit_gap": bench_tiny.LIMIT}})
     return check.judge(types.SimpleNamespace(cell=cell, requests=reqs), w,
                        seed=5)
 
@@ -102,18 +107,18 @@ def _decode(cfg, w, prompt, n, mode):
     forward per token."""
     seq = list(prompt)
     for _ in range(n):
-        z = dense_gqa.logits_at(cfg, w, np.array(seq), [len(seq) - 1],
-                                mode=mode)[0]
+        z = KIND.logits_at(cfg, w, np.array(seq), [len(seq) - 1],
+                           mode=mode)[0]
         seq.append(int(z.argmax()))
     return seq[len(prompt):]
 
 
 def test_sound_tokens_pass_and_the_control_fails(served):
     cfg, w, prompts, out, _ = served
-    sound = [check.served_gaps(cfg, w, _R(p, t)).max()
+    sound = [check.served_gaps(CELL, w, _R(p, t)).max()
              for p, t in zip(prompts, out)]
     assert max(sound) <= bench_tiny.LIMIT
-    control = [check.control_gaps(cfg, w, _R(p, t)).max()
+    control = [check.control_gaps(CELL, w, _R(p, t)).max()
                for p, t in zip(prompts, out)]
     assert max(control) > 3 * bench_tiny.LIMIT
 
